@@ -351,7 +351,7 @@ def _kernel_entries():
     def run_wsum():
         from repro.kernels.weighted_sum.kernel import weighted_sum_pallas
         rng = np.random.default_rng(6)
-        G = jnp.asarray(rng.normal(size=(5000, W)), jnp.float32)
+        G = jnp.asarray(rng.normal(size=(W, 5000)), jnp.float32)
         c = jnp.asarray(rng.normal(size=(W,)), jnp.float32)
         return _ck(lambda g, cc: weighted_sum_pallas(g, cc, interpret=True),
                    G, c, n_sites=1, name="weighted_sum_pallas")

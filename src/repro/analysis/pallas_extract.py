@@ -9,7 +9,7 @@ the substrate that opens the box:
 
 * :func:`find_pallas_calls` walks a jaxpr (through pjit / cond / scan /
   shard_map bodies) and returns one :class:`PallasSite` per call with
-  the grid, per-operand :class:`Block` descriptors (block shape, padded
+  the grid, per-operand :class:`Block` descriptors (block shape,
   operand shape, dtype, index-map jaxpr) and the raw kernel body jaxpr.
 * :meth:`PallasSite.visits` **concretely evaluates** every index map
   over the full grid product — grids here are small and static (the
@@ -34,7 +34,7 @@ from functools import cached_property
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 __all__ = ["Block", "PallasSite", "find_pallas_calls", "grid_points",
            "MAX_GRID_POINTS"]
@@ -52,8 +52,10 @@ def grid_points(grid: tuple[int, ...]):
 
 
 def _int_block_shape(block_shape) -> tuple[int, ...]:
-    """BlockSpec dims as plain ints (mapped/squeezed dims count as 1)."""
-    return tuple(d if isinstance(d, int) else 1 for d in block_shape)
+    """BlockSpec dims as plain ints (``Blocked(n)`` -> n; squeezed,
+    element and mapped dims count as 1)."""
+    return tuple(d if isinstance(d, int) else getattr(d, "block_size", 1)
+                 for d in block_shape)
 
 
 @dataclass(frozen=True)
@@ -61,8 +63,8 @@ class Block:
     """One operand of a ``pallas_call``: its tiling and index map.
 
     ``array_shape`` is the shape of the operand the caller actually
-    passed (the *padded* array — wrappers pad before dispatch), so
-    in-bounds reasoning over ``block_shape`` x index map is exact.
+    passed (after any padding the wrapper does), so in-bounds reasoning
+    over ``block_shape`` x index map is exact.
     """
 
     role: str                               # "in" | "out"
@@ -119,7 +121,7 @@ def _eval_vectorized(closed: jax_core.ClosedJaxpr, grid):
         return {}
 
     def one(row):
-        outs = jax_core.eval_jaxpr(closed.jaxpr, closed.consts,
+        outs = jax.core.eval_jaxpr(closed.jaxpr, closed.consts,
                                    *[row[i] for i in range(pts.shape[1])])
         return tuple(jnp.asarray(o, jnp.int32) for o in outs)
 
@@ -248,7 +250,7 @@ def _site_from_eqn(eqn, scope: str) -> PallasSite:
     for i, bm in enumerate(mappings):
         role = "in" if i < gm.num_inputs else "out"
         pos = i if role == "in" else i - gm.num_inputs
-        sds = bm.array_shape_dtype
+        sds = bm.array_aval
         blocks.append(Block(
             role=role, position=pos,
             block_shape=_int_block_shape(bm.block_shape),

@@ -6,8 +6,9 @@ Families (``K`` prefix = kernel-level; catalog in
 docs/static_analysis.md):
 
 ``ktiling``
-    Every output block is covered by the grid, every visited block is
-    in-bounds for the *padded* operand, and each output block is written
+    Every output block is covered by the grid, every visited block
+    starts inside its operand (a ragged edge block may run past the
+    end), and each output block is written
     by exactly one grid index along the axes its index map depends on —
     overlap along a dependent (non-revisit) axis means two unrelated
     grid steps race on the same tile.
@@ -43,7 +44,7 @@ from dataclasses import dataclass
 
 import jax.numpy as jnp
 import numpy as np
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.analysis.findings import Finding
 from repro.analysis.pallas_extract import (Block, PallasSite,
@@ -91,16 +92,20 @@ def check_kernel_tiling(graph_or_sites, *, name: str = "") -> list[Finding]:
         for block in site.blocks:
             visits = site.visits(block)
             for bidx in visits:
+                # A ragged edge block (it starts inside the operand and
+                # runs past its end) is legal Pallas: the overhang is
+                # padded on read and dropped on write.  A block that
+                # starts outside the operand is not.
                 oob = [k for k, (b, bs, a) in enumerate(
                     zip(bidx, block.block_shape, block.array_shape))
-                    if b < 0 or (b + 1) * bs > a]
+                    if b < 0 or b * bs >= a]
                 if oob:
                     g0 = visits[bidx][0]
                     findings.append(Finding(
                         "ktiling", "oob-block", site.scope,
                         f"{_blk(site, block)} block {bidx} @ grid {g0}",
                         f"{_blk(site, block)}: block index {bidx} x block "
-                        f"shape {block.block_shape} overruns the padded "
+                        f"shape {block.block_shape} overruns the "
                         f"operand {block.array_shape} along dim(s) {oob} — "
                         "the kernel reads/writes out of bounds"))
             if block.role != "out":

@@ -45,7 +45,7 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.analysis.findings import Finding
 from repro.analysis.hlo import (COMP_HEADER_RE, DTYPE_BYTES, SHAPE_RE,

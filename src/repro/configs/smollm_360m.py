@@ -1,4 +1,4 @@
-"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-135M].
+"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-360M].
 
 32L d_model=960 15H (GQA kv=5) d_ff=2560 vocab=49152.  Llama recipe:
 RMSNorm, SwiGLU, RoPE, tied embeddings.  Note 15 heads do not divide the

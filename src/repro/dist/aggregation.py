@@ -174,10 +174,11 @@ def tree_combine(tree, c: jnp.ndarray, *, impl: str = "xla"):
     """
     def one(leaf):
         if impl != "xla":
-            # the kernel upcasts both operands to fp32 in VMEM, so c keeps
-            # full precision end to end; only the output is leaf-dtype.
+            # the kernel reads the worker-major (W, n) view in place and
+            # upcasts both operands to fp32 in VMEM, so c keeps full
+            # precision end to end; only the output is leaf-dtype.
             d = weighted_sum_kernel(
-                leaf.reshape(leaf.shape[0], -1).T,
+                leaf.reshape(leaf.shape[0], -1),
                 c.astype(jnp.float32), impl=impl)
             return d.reshape(leaf.shape[1:])
         # contract in fp32 (c stays fp32, bf16 leaves accumulate in fp32
@@ -249,26 +250,30 @@ def _gram_weights(K: jnp.ndarray, cfg: AggregatorConfig,
 
     ``mask`` restricts every rule to the active worker subset (masked Gram
     rows — see repro.dist.membership); c is zero at inactive workers.
+    The (W, W) solves are tiny, so their matmuls run in fp32 on every
+    backend: the TPU's default precision would round fp32 operands to
+    bf16, and the weights decide which workers the update trusts.
     """
     p = K.shape[0]
-    if cfg.name == "flag":
-        return fa_weights_from_gram(K, cfg.flag, mask=mask)
-    if cfg.name == "pca":
-        pca_cfg = FlagConfig(m=cfg.flag.m, lam=0.0, regularizer="none",
-                             n_iter=1)
-        return fa_weights_from_gram(K, pca_cfg, mask=mask)
-    if cfg.name == "mean":
-        if mask is None:
-            return jnp.full((p,), 1.0 / p, K.dtype), {}
-        m = mask.astype(K.dtype)
-        return m / jnp.maximum(jnp.sum(m), 1.0), {}
-    if cfg.name == "geomed":
-        return _geomed_weights(K, mask=mask), {}
-    if cfg.name in ("krum", "multi_krum"):
-        if mask is None:
-            return _selection_weights(K, cfg.name, cfg.f, cfg.impl), {}
-        return aggregators.masked_selection_weights(
-            aggregators.sq_dists_from_gram(K), cfg.name, cfg.f, mask), {}
+    with jax.default_matmul_precision("highest"):
+        if cfg.name == "flag":
+            return fa_weights_from_gram(K, cfg.flag, mask=mask)
+        if cfg.name == "pca":
+            pca_cfg = FlagConfig(m=cfg.flag.m, lam=0.0, regularizer="none",
+                                 n_iter=1)
+            return fa_weights_from_gram(K, pca_cfg, mask=mask)
+        if cfg.name == "mean":
+            if mask is None:
+                return jnp.full((p,), 1.0 / p, K.dtype), {}
+            m = mask.astype(K.dtype)
+            return m / jnp.maximum(jnp.sum(m), 1.0), {}
+        if cfg.name == "geomed":
+            return _geomed_weights(K, mask=mask), {}
+        if cfg.name in ("krum", "multi_krum"):
+            if mask is None:
+                return _selection_weights(K, cfg.name, cfg.f, cfg.impl), {}
+            return aggregators.masked_selection_weights(
+                aggregators.sq_dists_from_gram(K), cfg.name, cfg.f, mask), {}
     raise KeyError(cfg.name)
 
 

@@ -54,7 +54,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.analysis.contract import contract
@@ -135,10 +134,10 @@ def _leafwise_shard_map(leaves, mesh: Mesh, axes: tuple[str, ...], fn,
         return tuple(fn(x.reshape(W, -1), *extras_).reshape(1, -1)
                      for x in xs)
 
-    outs = shard_map(local, mesh=mesh,
-                     in_specs=(P(),) + (spec_in,) * len(views),
-                     out_specs=(spec_out,) * len(views),
-                     check_rep=False)(tuple(extras), *views)
+    outs = jax.shard_map(local, mesh=mesh,
+                         in_specs=(P(),) + (spec_in,) * len(views),
+                         out_specs=(spec_out,) * len(views),
+                         check_vma=False)(tuple(extras), *views)
     return [_from_view(o, n, leaf.shape[1:], mesh, axes)
             for o, n, leaf in zip(outs, ns, leaves)]
 
@@ -175,8 +174,8 @@ def sharded_tree_gram(tree, mesh: Mesh, *, sketch_stride: int = 1,
                       gram_dtype=gram_dtype, impl=impl)
         return jax.lax.psum(K, axes)
 
-    return shard_map(local, mesh=mesh, in_specs=(spec_in,) * len(views),
-                     out_specs=P(), check_rep=False)(*views)
+    return jax.shard_map(local, mesh=mesh, in_specs=(spec_in,) * len(views),
+                         out_specs=P(), check_vma=False)(*views)
 
 
 def sharded_tree_combine(tree, c: jnp.ndarray, mesh: Mesh, *,

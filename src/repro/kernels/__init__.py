@@ -6,13 +6,15 @@ the n-scale work to three memory-bound streaming ops, each implemented as a
 Pallas kernel with explicit BlockSpec VMEM tiling:
 
   gram/          K = G^T G        -- blocked tall-skinny matmul, fp32 VMEM acc
-  weighted_sum/  d = G @ c        -- fused weighted combine of worker gradients
+  weighted_sum/  d = c @ G        -- fused weighted combine of worker gradients
   coord_stats/   median/trimmed/  -- odd-even-transposition sort network over
                  meamed/phocas      the (tiny) worker axis, blocked over n
   flash_attn/    online-softmax attention (serving path of the dense archs)
 
-Each kernel ships ``ops.py`` (jit'd public wrapper; ``interpret=`` defaults
-to True off-TPU so the same code path runs in CI) and ``ref.py`` (pure-jnp
-oracle).  ``tests/test_kernels_*.py`` sweep shapes and dtypes asserting
-allclose against the oracle.
+Each kernel ships ``ops.py`` (public wrapper choosing the backend from
+``impl=``: ``pallas_call`` on TPU, the XLA path elsewhere, the Pallas
+interpreter only when asked for with ``impl="pallas_interpret"`` or
+``interpret=True``, as CI does) and ``ref.py`` (pure-jnp oracle).
+``tests/test_kernels_*.py`` sweep shapes and dtypes asserting allclose
+against the oracle.
 """

@@ -209,7 +209,7 @@ def _make_masked_kernel(op: str, p: int, f: int):
                    static_argnames=("op", "f", "block_n", "interpret"))
 def coord_stats_pallas(Gw: jnp.ndarray, mask: jnp.ndarray | None = None, *,
                        op: str, f: int = 1, block_n: int = 2048,
-                       interpret: bool = True):
+                       interpret: bool = False):
     """Coordinate-wise robust stat over workers.  Gw: (p, n) -> (n,) fp32.
 
     Args:
@@ -291,7 +291,7 @@ def _make_krum_kernel(p: int, f: int):
 
 @functools.partial(jax.jit, static_argnames=("f", "interpret"))
 def krum_scores_pallas(D2: jnp.ndarray, *, f: int = 1,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool = False) -> jnp.ndarray:
     """Krum score per worker from (p, p) squared distances -> (p,) fp32.
 
     Each worker's score is the sum of its p - f - 2 smallest distances to
@@ -354,7 +354,7 @@ def _make_bulyan_kernel(p: int, f: int):
 
 @functools.partial(jax.jit, static_argnames=("f", "interpret"))
 def bulyan_select_pallas(D2: jnp.ndarray, *, f: int = 1,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool = False) -> jnp.ndarray:
     """Bulyan's recursive Multi-Krum selection, fused into ONE kernel.
 
     All theta = max(p - 2f, 1) selection rounds run inside a single

@@ -83,7 +83,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
 def flash_attn_pallas(q, k, v, *, causal: bool = True,
                       window: int | None = None, scale: float | None = None,
                       block_q: int = 128, block_k: int = 128,
-                      interpret: bool = True):
+                      interpret: bool = False):
     """q: (b, h, sq, d), k/v: (b, h, sk, d) -> (b, h, sq, d)."""
     b, h, sq, d = q.shape
     sk = k.shape[2]

@@ -6,7 +6,7 @@ Two kernels live here:
   per (n, p) matrix; the *looped* tree path dispatches it once per leaf).
 * :func:`tree_gram_pallas` — the fused one-pass tree kernel: the whole
   worker-major gradient row-stack (every leaf concatenated, (W, N)) streams
-  through a single ``pallas_call`` as fixed-size (W_pad, block_n) chunks
+  through a single ``pallas_call`` as fixed-size (W, block_n) chunks
   into one fp32 accumulator.  ``sketch_stride`` is folded into the index
   map (grid step j reads the chunk at block index j*stride) so the sketch
   never materializes a strided+scaled copy; the wrapper rescales once by
@@ -14,9 +14,12 @@ Two kernels live here:
 
 TPU mapping (both).  Tiles stream HBM -> VMEM; the fp32 accumulator lives
 in the *output* VMEM block, which every grid step revisits (index_map is
-constant) — the canonical Pallas reduction pattern.  The worker axis is
-padded to the 128-lane width once per call; zero padding contributes zeros
-to K, removed by the wrapper.  Contractions are issued with
+constant) — the canonical Pallas reduction pattern.  :func:`gram_pallas`
+pads its worker (lane) axis to 128 once per call.  :func:`tree_gram_pallas`
+reads the (W, N) stack in place: its blocks are (W, block_n) with the
+worker axis as the full block dim, so nothing is padded or copied in HBM
+(a 128-row pad of a whole-model stack would be 128x the gradient bytes);
+the ragged last chunk is zero-masked in VMEM.  Contractions are issued with
 preferred_element_type=float32 so bf16 gradients accumulate in fp32 (bf16
 Gram accumulation is one of the §Perf experiments — see ops.gram(precision=...)).
 """
@@ -49,7 +52,7 @@ def _gram_kernel(g_ref, k_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
 def gram_pallas(G: jnp.ndarray, *, block_n: int = 1024,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool = False) -> jnp.ndarray:
     """K = G^T G via pallas_call.  G: (n, p); returns (p, p) fp32.
 
     The wrapper pads n up to a block multiple and p up to the 128-lane
@@ -71,47 +74,56 @@ def gram_pallas(G: jnp.ndarray, *, block_n: int = 1024,
     return K[:p, :p]
 
 
-def _tree_gram_kernel(x_ref, k_ref):
-    j = pl.program_id(0)
+def _make_tree_gram_kernel(n: int, block_n: int, stride: int):
+    ragged = n % block_n != 0
 
-    @pl.when(j == 0)
-    def _init():
-        k_ref[...] = jnp.zeros_like(k_ref)
+    def kernel(x_ref, k_ref):
+        j = pl.program_id(0)
 
-    x = x_ref[...]                                   # (w_pad, block_n)
-    k_ref[...] += jax.lax.dot_general(
-        x, x,
-        dimension_numbers=(((1,), (1,)), ((), ())),  # contract over n-chunk
-        preferred_element_type=jnp.float32,
-    )
+        @pl.when(j == 0)
+        def _init():
+            k_ref[...] = jnp.zeros_like(k_ref)
+
+        x = x_ref[...]                               # (W, block_n)
+        if ragged:
+            # the last chunk runs past n: its tail lanes hold whatever the
+            # DMA left there, so zero them before they reach the MXU.
+            col = (j * stride * block_n
+                   + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1))
+            x = jnp.where(col < n, x, jnp.zeros_like(x))
+        k_ref[...] += jax.lax.dot_general(
+            x, x,
+            dimension_numbers=(((1,), (1,)), ((), ())),  # contract n-chunk
+            precision=jax.lax.Precision.HIGHEST,         # fp32 on the MXU
+            preferred_element_type=jnp.float32,
+        )
+    return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("sketch_stride", "block_n",
                                              "interpret"))
 def tree_gram_pallas(X: jnp.ndarray, *, sketch_stride: int = 1,
                      block_n: int = 1024,
-                     interpret: bool = True) -> jnp.ndarray:
+                     interpret: bool = False) -> jnp.ndarray:
     """One-pass fused Gram:  K = scale * X_S X_S^T in a single pallas_call.
 
     X: (W, N) worker-major row-stack of every flattened gradient leaf
-    (bf16 or fp32).  X_S is the chunk subset of :func:`ref.chunk_schedule`
-    — with ``sketch_stride`` > 1 the grid visits every stride-th
-    (W_pad, block_n) chunk via the index map, skipping the rest of HBM
-    entirely.  Returns (W, W) fp32.
+    (bf16 or fp32), read in place.  X_S is the chunk subset of
+    :func:`ref.chunk_schedule` — with ``sketch_stride`` > 1 the grid visits
+    every stride-th (W, block_n) chunk via the index map, skipping the rest
+    of HBM entirely.  Returns (W, W) fp32.
     """
     w, n = X.shape
-    w_pad = max(128, -(-w // 128) * 128)
-    kept, n_pad, scale = chunk_schedule(n, block_n, sketch_stride)
-    Xp = jnp.zeros((w_pad, n_pad), X.dtype).at[:w, :n].set(X)
-
+    kept, _, scale = chunk_schedule(n, block_n, sketch_stride)
+    bn = n if n <= block_n else block_n          # one chunk: the full row
     stride = max(1, sketch_stride)
     K = pl.pallas_call(
-        _tree_gram_kernel,
+        _make_tree_gram_kernel(n, bn, stride),
         grid=(kept,),
-        in_specs=[pl.BlockSpec((w_pad, block_n), lambda j: (0, j * stride))],
-        out_specs=pl.BlockSpec((w_pad, w_pad), lambda j: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((w_pad, w_pad), jnp.float32),
+        in_specs=[pl.BlockSpec((w, bn), lambda j: (0, j * stride))],
+        out_specs=pl.BlockSpec((w, w), lambda j: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((w, w), jnp.float32),
         interpret=interpret,
-    )(Xp)
-    K = K[:w, :w]
+        name="tree_gram",
+    )(X)
     return K * scale if scale != 1.0 else K

@@ -12,9 +12,9 @@ sample the identical coordinate set (:func:`ref.chunk_schedule`), so
 ``sketch_stride`` means the same thing everywhere: keep every stride-th
 block_n-wide chunk, rescale by the exact inverse sampling fraction.
 
-Callers pick the backend via ``impl=``; the distributed aggregator
-defaults to ``xla`` so the multi-pod dry-run lowers on the host platform,
-and flips to ``pallas`` on real TPU via config.
+Callers pick the backend via ``impl=``; ``AggregatorConfig`` defaults to
+``xla`` (the dry-run and the tests), and the training launcher passes
+``pallas``.
 
 ``impl`` convention (shared by every ``kernels/*/ops.py``):
 

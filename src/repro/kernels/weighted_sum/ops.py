@@ -8,7 +8,8 @@ from repro.kernels.weighted_sum.ref import weighted_sum_ref
 
 
 def weighted_sum(G, c, *, impl: str = "xla", block_n: int = 2048):
-    """d = G @ c. impl: 'xla' | 'pallas' | 'pallas_interpret'."""
+    """d = c @ G for worker-major G (W, n), c (W,).
+    impl: 'xla' | 'pallas' | 'pallas_interpret'."""
     if impl == "xla":
         return weighted_sum_ref(G, c)
     if impl == "pallas":

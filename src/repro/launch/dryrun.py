@@ -218,8 +218,6 @@ def lower_one(arch: str, shape_name: str, *, multi_pod: bool,
     # --- analyses ---
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per computation
-        cost = cost[0] if cost else {}
     try:
         hlo = compiled.as_text()
     except Exception:

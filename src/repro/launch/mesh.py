@@ -15,22 +15,19 @@ multi-pod; the ``model`` axis carries Megatron-style tensor parallelism.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _model_factor(n: int) -> int:
     """Widest model axis (of 4/2/1) that divides ``n`` with data > 1."""
     return next((m for m in (4, 2) if n % m == 0 and n > m), 1)
-
-
-def make_debug_mesh(n_devices: int | None = None):
-    """Tiny (data, model) mesh over whatever devices exist (CPU tests)."""
-    return make_host_mesh(n_devices)
 
 
 def make_host_mesh(n_devices: int | None = None):

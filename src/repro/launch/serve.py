@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, reduce_for_smoke
 from repro.dist.serve_step import build_serve_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer
 
 
@@ -26,6 +27,7 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.debug:

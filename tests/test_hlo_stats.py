@@ -19,10 +19,10 @@ SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import json
     import jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.analysis.hlo import parse_collectives
 
-    mesh = jax.make_mesh((4,), ("model",))
+    mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
     W_SH = NamedSharding(mesh, P(None, "model"))
     R_SH = NamedSharding(mesh, P(None, None))
 

@@ -106,7 +106,7 @@ class TestWeightedSumKernel:
     @pytest.mark.parametrize("n,p", [(64, 3), (1000, 15), (4096, 32), (513, 7)])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_matches_ref(self, rng, n, p, dtype):
-        G = _rand(rng, (n, p), dtype)
+        G = _rand(rng, (p, n), dtype)             # worker-major (W, n)
         c = _rand(rng, (p,), jnp.float32)
         got = weighted_sum_pallas(G, c, block_n=256, interpret=True)
         want = weighted_sum_ref(G, c)

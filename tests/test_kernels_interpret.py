@@ -97,7 +97,7 @@ def test_flash_attn_decode_bf16_interpret(prng):
 
 
 def test_weighted_sum_interpret(prng):
-    G = jnp.asarray(prng.normal(size=(500, 6)), jnp.float32)
+    G = jnp.asarray(prng.normal(size=(6, 500)), jnp.float32)  # (W, n)
     c = jnp.asarray(prng.normal(size=(6,)), jnp.float32)
     got = weighted_sum_pallas(G, c, block_n=256, interpret=True)
     np.testing.assert_allclose(got, weighted_sum_ref(G, c),

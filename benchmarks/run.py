@@ -14,7 +14,7 @@ import time
 
 from benchmarks import (augmentation, batch_size, byzantine_tolerance,
                         comm_loss, lambda_sweep, membership_churn,
-                        other_attacks, scalability, wallclock)
+                        other_attacks, scalability)
 
 SUITES = {
     "byzantine_tolerance": lambda q: byzantine_tolerance.run(
@@ -30,8 +30,6 @@ SUITES = {
     "lambda_sweep": lambda q: lambda_sweep.run(
         steps=20 if q else 35, lams=(0.1, 7.0) if q else
         (0.1, 1.0, 3.0, 7.0, 21.0)),
-    "wallclock": lambda q: wallclock.run(
-        ns=(10_000, 100_000) if q else (10_000, 100_000, 1_000_000)),
     "membership_churn": lambda q: membership_churn.run(
         steps=16 if q else 40,
         aggs=("flag", "krum", "mean") if q

@@ -1,4 +1,4 @@
-"""Parse collective ops out of compiled (SPMD-partitioned) HLO text.
+"""Parse compiled (SPMD-partitioned) HLO text: collectives, costs, scopes.
 
 ``cost_analysis()`` does not report collective traffic — and it counts
 ``while`` bodies once — so the roofline's collective term comes from here:
@@ -20,6 +20,12 @@
    by the product of enclosing-loop trip counts (nested scans compose), so
    scanned-layer models report the same collective volume as unrolled ones
    (validated in tests/test_hlo_stats.py and against an unrolled dry-run).
+
+:func:`op_scopes` maps every instruction to its ``jax.named_scope`` path,
+and :func:`attributed_scopes` also gives one to the instructions XLA made
+without any; that is how a profiler's op events (named by instruction) are
+put back into the train step's stages (docs/architecture.md, "Stage
+scopes").
 """
 
 from __future__ import annotations
@@ -287,3 +293,91 @@ def parse_collectives(hlo_text: str, total_devices: int) -> CollectiveStats:
     return CollectiveStats(dict(per_bytes), dict(per_count),
                            sum(per_bytes.values()),
                            {b: t for _, b, t in while_edges})
+
+
+OP_NAME_RE = re.compile(r'^(?:ROOT\s+)?%?([\w.\-]+)\s*=.*\bmetadata=\{[^}]*'
+                        r'\bop_name="([^"]*)"')
+
+
+def op_scopes(hlo_text: str) -> dict[str, str]:
+    """``{instruction name: op_name path}`` of an optimized HLO module.
+
+    Every instruction that carries ``metadata={op_name=...}`` is listed,
+    in every computation: entry, ``while`` bodies and conditions, fusions.
+    Instruction names are unique within a module, and they are the names a
+    profiler gives the module's op events.  The path is JAX's name stack,
+    e.g. ``jit(step)/grad/vmap(transpose(jvp()))/while/body/.../mlp/dot``.
+    """
+    out = {}
+    for line in hlo_text.splitlines():
+        m = OP_NAME_RE.match(line.strip())
+        if m:
+            out[m.group(1)] = m.group(2)
+    return out
+
+
+INSTR_RE = re.compile(r"^(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$")
+
+
+def attributed_scopes(hlo_text: str) -> dict[str, str]:
+    """:func:`op_scopes`, with a path for the instructions that have none.
+
+    XLA's own passes make instructions without ``op_name``: layout copies,
+    the loops and ``dynamic-update-slice`` chains it rewrites a big
+    ``concatenate`` or ``reshape`` into, async copy halves.  Each such
+    instruction takes the path of the nearest instruction it feeds that
+    has one (breadth first, in operand order), else of the nearest one
+    that feeds it, else that of the instruction that calls its computation
+    (the ``while`` that runs a loop body).  Only paths from metadata are
+    searched, so the result does not depend on the order of the visits.
+    """
+    named = op_scopes(hlo_text)
+    comp_of, refs, comp = {}, {}, None
+    for line in hlo_text.splitlines():
+        line = line.strip()
+        m = COMP_HEADER_RE.match(line)
+        if m:
+            comp = m.group(1)
+            continue
+        m = INSTR_RE.match(line)
+        if m and comp is not None:
+            comp_of[m.group(1)] = comp
+            refs[m.group(1)] = OPERAND_RE.findall(m.group(2))
+    operands, users, caller = {}, defaultdict(list), {}
+    for name, tokens in refs.items():
+        operands[name] = [t for t in tokens if t in comp_of]
+        for t in operands[name]:
+            users[t].append(name)
+        for t in tokens:
+            if t not in comp_of:                # a computation it calls
+                caller.setdefault(t, name)
+
+    def nearest(start, edges):
+        seen, frontier = {start}, [start]
+        while frontier:
+            nxt = []
+            for x in frontier:
+                for y in edges.get(x, ()):
+                    if y in named:
+                        return named[y]
+                    if y not in seen:
+                        seen.add(y)
+                        nxt.append(y)
+            frontier = nxt
+        return None
+
+    out = dict(named)
+    todo = [n for n in refs if n not in named]
+    while todo:                  # a loop body waits for its caller's path
+        left = []
+        for name in todo:
+            path = (nearest(name, users) or nearest(name, operands)
+                    or out.get(caller.get(comp_of[name])))
+            if path is None:
+                left.append(name)
+            else:
+                out[name] = path
+        if len(left) == len(todo):
+            break
+        todo = left
+    return out

@@ -14,7 +14,7 @@ flat stack.  Instead it exploits two structural facts:
   ``repro.kernels.gram`` issues a *single* ``pallas_call`` for the whole
   pytree (Pallas on TPU, XLA elsewhere; a per-shard psum on a real mesh),
   with the legacy per-leaf loop kept behind ``fused=False`` for the
-  benchmarks.
+  tests.
 * **Combine linearity** — any rule whose output is a fixed linear
   combination ``d = G^T c`` of worker gradients applies leafwise
   (``tree_combine``), a weighted reduction over the worker axis.
@@ -127,7 +127,7 @@ def tree_gram(tree, sketch_stride: int = 1, *, gram_dtype: str = "float32",
     exact inverse sampling fraction (diagonal-unbiased; weights only — the
     combine stays exact).  ``fused=False`` keeps the per-leaf loop (one
     dispatch + re-pad per leaf, element-stride sketching) as the
-    reference/comparison path the benchmarks time against.
+    reference path the tests compare against.
 
     Args:
       tree: worker-major pytree, every leaf shaped ``(W, ...)``.
@@ -146,17 +146,19 @@ def tree_gram(tree, sketch_stride: int = 1, *, gram_dtype: str = "float32",
     leaves = jax.tree.leaves(tree)
     if not leaves:
         raise ValueError("tree_gram: empty gradient pytree")
-    if fused:
-        return tree_gram_fused(leaves, sketch_stride=sketch_stride,
-                               gram_dtype=gram_dtype, impl=impl)
-    W = leaves[0].shape[0]
-    K = jnp.zeros((W, W), jnp.float32)
-    for leaf in leaves:
-        M, scale = _leaf_matrix(leaf, sketch_stride, gram_dtype)
-        # kernels.gram computes G^T G for column-major (n, p) input in fp32;
-        # the sketch rescale is applied to the fp32 result (post-cast).
-        K = K + gram_kernel(M.T, impl=impl) * scale
-    return K
+    with jax.named_scope("gram"):
+        if fused:
+            return tree_gram_fused(leaves, sketch_stride=sketch_stride,
+                                   gram_dtype=gram_dtype, impl=impl)
+        W = leaves[0].shape[0]
+        K = jnp.zeros((W, W), jnp.float32)
+        for leaf in leaves:
+            M, scale = _leaf_matrix(leaf, sketch_stride, gram_dtype)
+            # kernels.gram computes G^T G for column-major (n, p) input in
+            # fp32; the sketch rescale is applied to the fp32 result
+            # (post-cast).
+            K = K + gram_kernel(M.T, impl=impl) * scale
+        return K
 
 
 def tree_combine(tree, c: jnp.ndarray, *, impl: str = "xla"):
@@ -190,7 +192,8 @@ def tree_combine(tree, c: jnp.ndarray, *, impl: str = "xla"):
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         return d.astype(leaf.dtype)
-    return jax.tree.map(one, tree)
+    with jax.named_scope("combine"):
+        return jax.tree.map(one, tree)
 
 
 # ---------------------------------------------------------------------------
@@ -255,7 +258,7 @@ def _gram_weights(K: jnp.ndarray, cfg: AggregatorConfig,
     bf16, and the weights decide which workers the update trusts.
     """
     p = K.shape[0]
-    with jax.default_matmul_precision("highest"):
+    with jax.named_scope("solve"), jax.default_matmul_precision("highest"):
         if cfg.name == "flag":
             return fa_weights_from_gram(K, cfg.flag, mask=mask)
         if cfg.name == "pca":
@@ -368,11 +371,12 @@ def aggregate_tree(tree, cfg: AggregatorConfig, *, gram=None, mask=None,
         # coord_stat routes cfg.impl — the streaming Pallas selection
         # network or the jnp references — with identical (masked)
         # semantics either way.
-        d = jax.tree.map(
-            lambda g: coord_stat(g.reshape(W, -1), op=cfg.name, f=cfg.f,
-                                 impl=cfg.impl, mask=mask
-                                 ).reshape(g.shape[1:]),
-            tree)
+        with jax.named_scope("coord_stats"):
+            d = jax.tree.map(
+                lambda g: coord_stat(g.reshape(W, -1), op=cfg.name, f=cfg.f,
+                                     impl=cfg.impl, mask=mask
+                                     ).reshape(g.shape[1:]),
+                tree)
         if mask is None:
             return d, {"weights": jnp.full((W,), 1.0 / W, jnp.float32)}
         wa = jnp.maximum(jnp.sum(mask), 1.0)
@@ -384,9 +388,10 @@ def aggregate_tree(tree, cfg: AggregatorConfig, *, gram=None, mask=None,
         K = gram if gram is not None else tree_gram(
             tree, cfg.sketch_stride, gram_dtype=cfg.gram_dtype,
             impl=cfg.impl)
-        D2 = aggregators.sq_dists_from_gram(K)
         if mask is None:
-            picks = bulyan_select_op(D2, f=cfg.f, impl=cfg.impl)
+            with jax.named_scope("solve"):
+                D2 = aggregators.sq_dists_from_gram(K)
+                picks = bulyan_select_op(D2, f=cfg.f, impl=cfg.impl)
             theta = picks.shape[0]
             # Bulyan's coordinate stage IS MeaMed with f' = 2f on the
             # selected stack: mean of max(theta - 2f, 1) values closest to
@@ -396,12 +401,17 @@ def aggregate_tree(tree, cfg: AggregatorConfig, *, gram=None, mask=None,
                 return coord_stat(S, op="meamed", f=2 * cfg.f,
                                   impl=cfg.impl).reshape(g.shape[1:])
 
-            d = jax.tree.map(one, tree)
-            c = jnp.zeros((W,), jnp.float32).at[picks].add(1.0 / theta)
+            with jax.named_scope("coord_stats"):
+                d = jax.tree.map(one, tree)
+            with jax.named_scope("solve"):
+                c = jnp.zeros((W,), jnp.float32).at[picks].add(1.0 / theta)
             return d, {"weights": c}
 
-        selected, theta = aggregators.masked_bulyan_select(D2, cfg.f, mask)
-        sel_f = selected.astype(jnp.float32)
+        with jax.named_scope("solve"):
+            D2 = aggregators.sq_dists_from_gram(K)
+            selected, theta = aggregators.masked_bulyan_select(D2, cfg.f,
+                                                               mask)
+            sel_f = selected.astype(jnp.float32)
 
         def one_masked(g):
             # masked MeaMed over the selected workers: W_a = theta, so the
@@ -410,7 +420,8 @@ def aggregate_tree(tree, cfg: AggregatorConfig, *, gram=None, mask=None,
                               impl=cfg.impl, mask=sel_f
                               ).reshape(g.shape[1:])
 
-        d = jax.tree.map(one_masked, tree)
+        with jax.named_scope("coord_stats"):
+            d = jax.tree.map(one_masked, tree)
         return d, {"weights": sel_f / jnp.maximum(theta, 1)}
 
     raise KeyError(f"unknown aggregator {cfg.name!r}; have "
@@ -497,13 +508,15 @@ def compressed_aggregate(tree, cfg: AggregatorConfig,
              "comm_ratio": jnp.asarray(bits_dense / bits)}
 
     if codec.gram_feed and cfg.name in GRAM_RULES and not comm.wants_ef:
-        payload = codec.encode(tree)
+        with jax.named_scope("codec"):
+            payload = codec.encode(tree)
         K = tree_gram(payload, gram_dtype=cfg.gram_dtype, impl=cfg.impl)
         d, aux = aggregate_tree(tree, cfg, gram=K, mask=mask,
                                 sharded=sharded)
         return d, {**aux, **stats}, ef
 
     use_ef = ef if comm.wants_ef else None
-    decoded, _, new_ef = ef_encode_decode(codec, tree, use_ef, mask=mask)
+    with jax.named_scope("codec"):
+        decoded, _, new_ef = ef_encode_decode(codec, tree, use_ef, mask=mask)
     d, aux = aggregate_tree(decoded, cfg, mask=mask, sharded=sharded)
     return d, {**aux, **stats}, (new_ef if comm.wants_ef else ef)

@@ -165,7 +165,6 @@ def sharded_tree_gram(tree, mesh: Mesh, *, sketch_stride: int = 1,
     if not leaves:
         raise ValueError("sharded_tree_gram: empty gradient pytree")
     axes = coord_axes(mesh) if axes is None else axes
-    views, _ = _views(leaves, mesh, axes)
     W = leaves[0].shape[0]
     spec_in = P(None, axes, None)
 
@@ -174,8 +173,11 @@ def sharded_tree_gram(tree, mesh: Mesh, *, sketch_stride: int = 1,
                       gram_dtype=gram_dtype, impl=impl)
         return jax.lax.psum(K, axes)
 
-    return jax.shard_map(local, mesh=mesh, in_specs=(spec_in,) * len(views),
-                         out_specs=P(), check_vma=False)(*views)
+    with jax.named_scope("gram"):
+        views, _ = _views(leaves, mesh, axes)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(spec_in,) * len(views),
+                             out_specs=P(), check_vma=False)(*views)
 
 
 def sharded_tree_combine(tree, c: jnp.ndarray, mesh: Mesh, *,
@@ -204,7 +206,8 @@ def sharded_tree_combine(tree, c: jnp.ndarray, mesh: Mesh, *,
     def one(M, c_):
         return tree_combine([M], c_, impl=impl)[0]
 
-    outs = _leafwise_shard_map(leaves, mesh, axes, one, c)
+    with jax.named_scope("combine"):
+        outs = _leafwise_shard_map(leaves, mesh, axes, one, c)
     return treedef.unflatten(outs)
 
 
@@ -249,15 +252,18 @@ def sharded_aggregate_tree(tree, cfg, *, mesh: Mesh, gram=None, mask=None):
         # is bit-identical to single-device on either backend.
         from repro.kernels.coord_stats.ops import coord_stat
         if mask is None:
-            outs = _leafwise_shard_map(
-                leaves, mesh, axes,
-                lambda M: coord_stat(M, op=cfg.name, f=cfg.f, impl=cfg.impl))
+            with jax.named_scope("coord_stats"):
+                outs = _leafwise_shard_map(
+                    leaves, mesh, axes,
+                    lambda M: coord_stat(M, op=cfg.name, f=cfg.f,
+                                         impl=cfg.impl))
             return treedef.unflatten(outs), {
                 "weights": jnp.full((W,), 1.0 / W, jnp.float32)}
-        outs = _leafwise_shard_map(
-            leaves, mesh, axes,
-            lambda M, m: coord_stat(M, op=cfg.name, f=cfg.f, impl=cfg.impl,
-                                    mask=m), mask)
+        with jax.named_scope("coord_stats"):
+            outs = _leafwise_shard_map(
+                leaves, mesh, axes,
+                lambda M, m: coord_stat(M, op=cfg.name, f=cfg.f,
+                                        impl=cfg.impl, mask=m), mask)
         wa = jnp.maximum(jnp.sum(mask), 1.0)
         return treedef.unflatten(outs), {"weights": mask / wa}
 
@@ -265,10 +271,11 @@ def sharded_aggregate_tree(tree, cfg, *, mesh: Mesh, gram=None, mask=None):
         # Selection is Gram-only (replicated); the trimmed mean over the
         # selected workers is coordinate-wise (shard-local).
         K = psummed_gram()
-        D2 = aggregators.sq_dists_from_gram(K)
         from repro.kernels.coord_stats.ops import bulyan_select, coord_stat
         if mask is None:
-            picks = bulyan_select(D2, f=cfg.f, impl=cfg.impl)
+            with jax.named_scope("solve"):
+                D2 = aggregators.sq_dists_from_gram(K)
+                picks = bulyan_select(D2, f=cfg.f, impl=cfg.impl)
             theta = picks.shape[0]
 
             # Bulyan's coordinate stage == MeaMed with f' = 2f on the
@@ -277,19 +284,25 @@ def sharded_aggregate_tree(tree, cfg, *, mesh: Mesh, gram=None, mask=None):
                 return coord_stat(M[picks_], op="meamed", f=2 * cfg.f,
                                   impl=cfg.impl)
 
-            outs = _leafwise_shard_map(leaves, mesh, axes, one, picks)
-            c = jnp.zeros((W,), jnp.float32).at[picks].add(1.0 / theta)
+            with jax.named_scope("coord_stats"):
+                outs = _leafwise_shard_map(leaves, mesh, axes, one, picks)
+            with jax.named_scope("solve"):
+                c = jnp.zeros((W,), jnp.float32).at[picks].add(1.0 / theta)
             return treedef.unflatten(outs), {"weights": c}
 
-        selected, theta = aggregators.masked_bulyan_select(D2, cfg.f, mask)
-        sel_f = selected.astype(jnp.float32)
+        with jax.named_scope("solve"):
+            D2 = aggregators.sq_dists_from_gram(K)
+            selected, theta = aggregators.masked_bulyan_select(D2, cfg.f,
+                                                               mask)
+            sel_f = selected.astype(jnp.float32)
 
         def one_masked(M, sel):
             # masked MeaMed with W_a = theta: keep-count max(theta-2f, 1).
             return coord_stat(M, op="meamed", f=2 * cfg.f, impl=cfg.impl,
                               mask=sel)
 
-        outs = _leafwise_shard_map(leaves, mesh, axes, one_masked, sel_f)
+        with jax.named_scope("coord_stats"):
+            outs = _leafwise_shard_map(leaves, mesh, axes, one_masked, sel_f)
         return treedef.unflatten(outs), {
             "weights": sel_f / jnp.maximum(theta, 1)}
 

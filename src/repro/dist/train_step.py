@@ -33,6 +33,11 @@ stage: every rule operates on the dynamic worker subset (masked Gram rows
 frozen, and membership changes never recompile (the mask is a traced
 value; all shapes stay (W, ...)).
 
+Each stage runs under a ``jax.named_scope`` — ``grad``, ``attack``,
+``aggregate``, ``optimizer``, ``telemetry`` — which XLA keeps as the
+``op_name`` of every instruction, so a device trace splits into stages
+(docs/architecture.md, "Stage scopes"; the names are a contract).
+
 When the configured codec needs error feedback (``tc.comm.wants_ef``) the
 step carries the per-worker EF memory explicitly: its signature becomes
 ``step(params, opt_state, batch, rng, step_idx, ef)`` returning
@@ -179,69 +184,78 @@ def build_train_step(cfg: ModelConfig, tc: TrainConfig, opt: Optimizer,
                 jax.tree.map(lambda t: t * inv, m))
 
     def core(params, opt_state, batch, rng, step_idx, ef):
-        grads, metrics_w = jax.vmap(worker_grad, in_axes=(None, 0))(
-            params, batch)
-        if grad_shardings is not None:
-            grads = jax.lax.with_sharding_constraint(grads, grad_shardings)
+        with jax.named_scope("grad"):
+            grads, metrics_w = jax.vmap(worker_grad, in_axes=(None, 0))(
+                params, batch)
+            if grad_shardings is not None:
+                grads = jax.lax.with_sharding_constraint(grads,
+                                                         grad_shardings)
 
         if tc.attack != "none" and tc.attack_f > 0:
-            grads = attacks.apply_attack_tree(tc.attack, grads, rng,
-                                              tc.attack_f)
+            with jax.named_scope("attack"):
+                grads = attacks.apply_attack_tree(tc.attack, grads, rng,
+                                                  tc.attack_f)
 
         W = jax.tree.leaves(grads)[0].shape[0]
-        if tc.faults.is_trivial:
-            mem, mask = None, None
-        else:
-            # Membership is a pure jnp function of the traced step index:
-            # the same compiled program serves every worker subset.
-            mem = membership_at(tc.faults, step_idx, W)
-            mask = mem.active.astype(jnp.float32)
+        with jax.named_scope("aggregate"):
+            if tc.faults.is_trivial:
+                mem, mask = None, None
+            else:
+                # Membership is a pure jnp function of the traced step
+                # index: the same compiled program serves every worker
+                # subset.
+                mem = membership_at(tc.faults, step_idx, W)
+                mask = mem.active.astype(jnp.float32)
 
-        if tc.sharded_agg:
-            # Sharded by construction: GSPMD redistributes the per-worker
-            # gradients straight into the coordinate-shard layout the
-            # sharded aggregation consumes — the (W, n) stack never
-            # gathers onto one device on its way to the aggregator.
-            grads = shard_grad_stack(grads)
+            if tc.sharded_agg:
+                # Sharded by construction: GSPMD redistributes the
+                # per-worker gradients straight into the coordinate-shard
+                # layout the sharded aggregation consumes — the (W, n)
+                # stack never gathers onto one device on its way to the
+                # aggregator.
+                grads = shard_grad_stack(grads)
 
-        d, agg_aux, new_ef = compressed_aggregate(
-            grads, tc.aggregator, tc.comm, ef, mask=mask,
-            sharded=tc.sharded_agg or None)
+            d, agg_aux, new_ef = compressed_aggregate(
+                grads, tc.aggregator, tc.comm, ef, mask=mask,
+                sharded=tc.sharded_agg or None)
 
-        lr = sched(step_idx)
-        updates, new_opt_state = opt.update(d, opt_state, params, lr)
-        new_params = apply_updates(params, updates)
-        if param_shardings is not None:
-            new_params = jax.lax.with_sharding_constraint(new_params,
-                                                          param_shardings)
+        with jax.named_scope("optimizer"):
+            lr = sched(step_idx)
+            updates, new_opt_state = opt.update(d, opt_state, params, lr)
+            new_params = apply_updates(params, updates)
+            if param_shardings is not None:
+                new_params = jax.lax.with_sharding_constraint(
+                    new_params, param_shardings)
 
-        c = agg_aux["weights"].astype(jnp.float32)
-        worker_norms = jnp.sqrt(sum(
-            jnp.sum(jnp.square(l.astype(jnp.float32)),
-                    axis=tuple(range(1, l.ndim)))
-            for l in jax.tree.leaves(grads)))
-        influence = jnp.abs(c) * worker_norms
-        influence = influence / jnp.maximum(jnp.sum(influence), 1e-20)
+        with jax.named_scope("telemetry"):
+            c = agg_aux["weights"].astype(jnp.float32)
+            worker_norms = jnp.sqrt(sum(
+                jnp.sum(jnp.square(l.astype(jnp.float32)),
+                        axis=tuple(range(1, l.ndim)))
+                for l in jax.tree.leaves(grads)))
+            influence = jnp.abs(c) * worker_norms
+            influence = influence / jnp.maximum(jnp.sum(influence), 1e-20)
 
-        if mask is None:
-            metrics = {k: jnp.mean(v) for k, v in metrics_w.items()}
-        else:
-            # honest telemetry: absent workers' slots hold garbage — the
-            # per-worker metric means cover the active subset only.
-            wa = jnp.maximum(jnp.sum(mask), 1.0)
-            metrics = {
-                k: jnp.sum(v * mask.reshape((W,) + (1,) * (v.ndim - 1)))
-                / (wa * (v.size // W))
-                for k, v in metrics_w.items()}
-        metrics["lr"] = lr
-        metrics["grad_global_norm"] = global_norm(d)
-        metrics["fa_weights"] = c
-        metrics["worker_influence"] = influence
-        metrics["comm_bits"] = agg_aux["comm_bits"]
-        metrics["comm_ratio"] = agg_aux["comm_ratio"]
-        if mem is not None:
-            metrics["active_workers"] = jnp.sum(mem.active.astype(jnp.int32))
-            metrics["worker_staleness"] = mem.staleness
+            if mask is None:
+                metrics = {k: jnp.mean(v) for k, v in metrics_w.items()}
+            else:
+                # honest telemetry: absent workers' slots hold garbage —
+                # the per-worker metric means cover the active subset only.
+                wa = jnp.maximum(jnp.sum(mask), 1.0)
+                metrics = {
+                    k: jnp.sum(v * mask.reshape((W,) + (1,) * (v.ndim - 1)))
+                    / (wa * (v.size // W))
+                    for k, v in metrics_w.items()}
+            metrics["lr"] = lr
+            metrics["grad_global_norm"] = global_norm(d)
+            metrics["fa_weights"] = c
+            metrics["worker_influence"] = influence
+            metrics["comm_bits"] = agg_aux["comm_bits"]
+            metrics["comm_ratio"] = agg_aux["comm_ratio"]
+            if mem is not None:
+                metrics["active_workers"] = jnp.sum(
+                    mem.active.astype(jnp.int32))
+                metrics["worker_staleness"] = mem.staleness
         return new_params, new_opt_state, metrics, new_ef
 
     if tc.comm.wants_ef:

@@ -240,6 +240,7 @@ def coord_stats_pallas(Gw: jnp.ndarray, mask: jnp.ndarray | None = None, *,
             out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
             interpret=interpret,
+            name="coord_stats_pallas",
         )(Gp)
         return out[0, :n]
 
@@ -253,6 +254,7 @@ def coord_stats_pallas(Gw: jnp.ndarray, mask: jnp.ndarray | None = None, *,
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
         interpret=interpret,
+        name="coord_stats_pallas",
     )(Gp, mp)
     return out[0, :n]
 
@@ -308,6 +310,7 @@ def krum_scores_pallas(D2: jnp.ndarray, *, f: int = 1,
         out_shape=jax.ShapeDtypeStruct((1, _pad_d2(D2).shape[1]),
                                        jnp.float32),
         interpret=interpret,
+        name="krum_scores_pallas",
     )(_pad_d2(D2))
     return out[0, :p]
 
@@ -374,6 +377,7 @@ def bulyan_select_pallas(D2: jnp.ndarray, *, f: int = 1,
         out_specs=pl.BlockSpec((1, Dp.shape[1]), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, Dp.shape[1]), jnp.int32),
         interpret=interpret,
+        name="bulyan_select_pallas",
     )(Dp)
     # ascending selection-round order; unselected carry the theta sentinel
     return jnp.argsort(order[0, :p], stable=True)[:theta]

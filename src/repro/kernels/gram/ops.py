@@ -93,7 +93,8 @@ def tree_gram_fused(leaves, *, sketch_stride: int = 1,
             leaves = [leaf.astype(target) for leaf in leaves]
         return tree_gram_pieces_ref(leaves, sketch_stride=sketch_stride,
                                     block_n=block_n)
-    X = pack_leaves(leaves, gram_dtype=gram_dtype)
+    with jax.named_scope("pack"):
+        X = pack_leaves(leaves, gram_dtype=gram_dtype)
     if impl == "pallas":
         return tree_gram_pallas(X, sketch_stride=sketch_stride,
                                 block_n=block_n, interpret=False)

@@ -33,8 +33,7 @@ def _model_factor(n: int) -> int:
 def make_host_mesh(n_devices: int | None = None):
     """(data, model) mesh over the FIRST ``n_devices`` host devices.
 
-    The sharded-aggregation tests and ``benchmarks/sharded_agg.py`` sweep
-    device counts on a single host
+    The sharded-aggregation tests sweep device counts on a single host
     (``XLA_FLAGS=--xla_force_host_platform_device_count=8``), which needs
     meshes over a *prefix* of the device list — ``jax.make_mesh`` insists
     on consuming every device, so this builds the Mesh explicitly.

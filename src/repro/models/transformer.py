@@ -75,31 +75,35 @@ def block_apply(p, x, cfg: ModelConfig, kind: str, *, positions,
     """Returns (x, new_cache, aux_losses)."""
     h = layers.apply_norm(p["norm1"], x, cfg.norm)
     new_cache = cache
-    if kind == "attn":
-        if decode:
-            out, new_cache = attention.attn_decode(p["mixer"], h, cfg, cache,
-                                                   step=step, ring=ring)
+    with jax.named_scope("attention"):       # the mixer, whichever kind
+        if kind == "attn":
+            if decode:
+                out, new_cache = attention.attn_decode(
+                    p["mixer"], h, cfg, cache, step=step, ring=ring)
+            else:
+                out = attention.attn_apply(p["mixer"], h, cfg,
+                                           positions=positions,
+                                           impl=attn_impl)
+        elif kind == "mlstm":
+            out, new_cache = ssm.mlstm_block_apply(
+                p["mixer"], h, cfg, cache, chunk=1 if decode else 256)
+        elif kind == "slstm":
+            out, new_cache = ssm.slstm_block_apply(p["mixer"], h, cfg, cache)
+        elif kind == "rglru":
+            out, new_cache = rglru_lib.rglru_block_apply(p["mixer"], h, cfg,
+                                                         cache)
         else:
-            out = attention.attn_apply(p["mixer"], h, cfg,
-                                       positions=positions, impl=attn_impl)
-    elif kind == "mlstm":
-        out, new_cache = ssm.mlstm_block_apply(p["mixer"], h, cfg, cache,
-                                               chunk=1 if decode else 256)
-    elif kind == "slstm":
-        out, new_cache = ssm.slstm_block_apply(p["mixer"], h, cfg, cache)
-    elif kind == "rglru":
-        out, new_cache = rglru_lib.rglru_block_apply(p["mixer"], h, cfg, cache)
-    else:
-        raise ValueError(kind)
+            raise ValueError(kind)
     x = x + out.astype(x.dtype)
 
     losses = {}
     if "ffn" in p:
         h = layers.apply_norm(p["norm2"], x, cfg.norm)
-        if is_moe:
-            out, losses = moe_lib.moe_apply(p["ffn"], h, cfg)
-        else:
-            out = mlp_lib.mlp_apply(p["ffn"], h, cfg)
+        with jax.named_scope("mlp"):         # dense MLP or MoE
+            if is_moe:
+                out, losses = moe_lib.moe_apply(p["ffn"], h, cfg)
+            else:
+                out = mlp_lib.mlp_apply(p["ffn"], h, cfg)
         x = x + out.astype(x.dtype)
     return x, new_cache, losses
 
@@ -342,6 +346,8 @@ def apply_stack(params, x, cfg: ModelConfig, *, positions, caches=None,
 
 
 def _logits(params, x, cfg: ModelConfig):
+    """Final norm, then the (tied or separate) unembedding."""
+    x = layers.apply_norm(params["final_norm"], x, cfg.norm)
     table = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = layers.unembed(table, x, jnp.dtype(cfg.compute_dtype))
     logits = layers.softcap(logits.astype(jnp.float32), cfg.logit_softcap)
@@ -350,24 +356,25 @@ def _logits(params, x, cfg: ModelConfig):
 
 def forward(params, batch, cfg: ModelConfig, *, attn_impl="xla"):
     """Training/eval forward.  Returns (loss, metrics)."""
-    x, positions, loss_mask = _embed_inputs(params, batch, cfg)
+    with jax.named_scope("embed"):
+        x, positions, loss_mask = _embed_inputs(params, batch, cfg)
     x, _, aux = apply_stack(params, x, cfg, positions=positions,
                             attn_impl=attn_impl)
-    x = layers.apply_norm(params["final_norm"], x, cfg.norm)
-    logits = _logits(params, x, cfg)
+    with jax.named_scope("lm_head"):
+        logits = _logits(params, x, cfg)
 
-    labels = batch["labels"]
-    if logits.shape[1] != labels.shape[1]:          # frontend prefix present
-        prefix = logits.shape[1] - labels.shape[1]
-        pad_lab = jnp.zeros((labels.shape[0], prefix), labels.dtype)
-        labels = jnp.concatenate([pad_lab, labels], axis=1)
-    if loss_mask is None:
-        loss_mask = jnp.ones(labels.shape, bool)
+        labels = batch["labels"]
+        if logits.shape[1] != labels.shape[1]:      # frontend prefix present
+            prefix = logits.shape[1] - labels.shape[1]
+            pad_lab = jnp.zeros((labels.shape[0], prefix), labels.dtype)
+            labels = jnp.concatenate([pad_lab, labels], axis=1)
+        if loss_mask is None:
+            loss_mask = jnp.ones(labels.shape, bool)
 
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-    denom = jnp.maximum(jnp.sum(loss_mask), 1)
-    loss = jnp.sum(nll * loss_mask) / denom
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
+        denom = jnp.maximum(jnp.sum(loss_mask), 1)
+        loss = jnp.sum(nll * loss_mask) / denom
     total = loss + sum(aux.values()) if aux else loss
     metrics = {"loss": loss, **aux,
                "ppl_proxy": jnp.exp(jnp.clip(loss, 0, 20.0))}
@@ -378,26 +385,29 @@ def decode_step(params, token, caches, step, cfg: ModelConfig, *,
                 max_len: int):
     """One-token serve step.  token: (B, 1) -> (logits (B,1,V), caches)."""
     cdt = jnp.dtype(cfg.compute_dtype)
-    x = layers.embed(params["embed"], token, cdt)
     B = token.shape[0]
     positions = jnp.broadcast_to(step[None, None], (B, 1))
-    if cfg.pos == "sinusoidal":
-        x = x + layers.sinusoidal_positions(positions, cfg.d_model).astype(cdt)
+    with jax.named_scope("embed"):
+        x = layers.embed(params["embed"], token, cdt)
+        if cfg.pos == "sinusoidal":
+            x = x + layers.sinusoidal_positions(positions,
+                                                cfg.d_model).astype(cdt)
     ring = attention.cache_is_ring(cfg, max_len)
     x, caches, _ = apply_stack(params, x, cfg, positions=positions,
                                caches=caches, decode=True, step=step,
                                ring=ring)
-    x = layers.apply_norm(params["final_norm"], x, cfg.norm)
-    return _logits(params, x, cfg), caches
+    with jax.named_scope("lm_head"):
+        return _logits(params, x, cfg), caches
 
 
 def prefill(params, batch, cfg: ModelConfig, *, attn_impl="xla"):
     """Full-sequence forward returning logits (inference prefill path)."""
-    x, positions, _ = _embed_inputs(params, batch, cfg)
+    with jax.named_scope("embed"):
+        x, positions, _ = _embed_inputs(params, batch, cfg)
     x, _, _ = apply_stack(params, x, cfg, positions=positions,
                           attn_impl=attn_impl)
-    x = layers.apply_norm(params["final_norm"], x, cfg.norm)
-    return _logits(params, x, cfg)
+    with jax.named_scope("lm_head"):
+        return _logits(params, x, cfg)
 
 
 # ---------------------------------------------------------------------------

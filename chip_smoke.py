@@ -159,15 +159,22 @@ def sharded_phase(argv) -> bool:
     """SGD steps with ``--sharded-agg`` on all devices vs one device."""
     import gc
 
+    from unittest import mock
+
     import jax
     import numpy as np
 
     from repro.launch import train
+    from repro.models import attention
 
     runs = {}
     for label, extra in (("sharded", ["--sharded-agg"]), ("one-device", [])):
-        res = train.main(argv + ["--optimizer", "sgd", "--steps",
-                                 str(SHARDED_STEPS)] + extra)
+        # on the mesh attention runs in XLA (attention.attend); the
+        # one-device run takes the same path, so that the two differ only
+        # in the aggregation
+        with mock.patch.object(attention, "_kernel_runs_here", lambda: False):
+            res = train.main(argv + ["--optimizer", "sgd", "--steps",
+                                     str(SHARDED_STEPS)] + extra)
         runs[label] = (res.losses, jax.device_get(res.params))
         del res
         gc.collect()

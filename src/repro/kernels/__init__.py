@@ -9,12 +9,15 @@ Pallas kernel with explicit BlockSpec VMEM tiling:
   weighted_sum/  d = c @ G        -- fused weighted combine of worker gradients
   coord_stats/   median/trimmed/  -- odd-even-transposition sort network over
                  meamed/phocas      the (tiny) worker axis, blocked over n
-  flash_attn/    online-softmax attention (serving path of the dense archs)
+  flash_attn/    online-softmax attention with its own backward (training
+                 and prefill of the attention archs on one TPU device)
 
-Each kernel ships ``ops.py`` (public wrapper choosing the backend from
-``impl=``: ``pallas_call`` on TPU, the XLA path elsewhere, the Pallas
-interpreter only when asked for with ``impl="pallas_interpret"`` or
-``interpret=True``, as CI does) and ``ref.py`` (pure-jnp oracle).
+The aggregation kernels ship ``ops.py`` (public wrapper choosing the
+backend from ``impl=``: ``pallas_call`` on TPU, the XLA path elsewhere,
+the Pallas interpreter only when asked for with
+``impl="pallas_interpret"`` or ``interpret=True``, as CI does); flash
+attention's dispatch lives in ``models/attention.py:attend``.  Each ships
+``ref.py`` (pure-jnp oracle).
 ``tests/test_kernels_*.py`` sweep shapes and dtypes asserting allclose
 against the oracle.
 """

@@ -126,7 +126,7 @@ def train_config(args) -> TrainConfig:
             name=args.aggregator, f=args.byzantine, impl="pallas",
             flag=FlagConfig(lam=lam,
                             regularizer="pairwise" if lam else "none")),
-        attack=args.attack, attack_f=args.byzantine,
+        attack=args.attack, attack_f=args.byzantine, attn_impl="pallas",
         comm=CommConfig(codec=args.codec,
                         error_feedback=False if args.no_ef else None),
         faults=get_fault_schedule(args.faults, W),

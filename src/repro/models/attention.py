@@ -8,8 +8,9 @@ Three execution paths:
   ``jax.checkpoint``-ed so the 4k training backward stores O(S) not O(S^2).
   Sliding-window attention takes a dynamic-slice fast path: each q-chunk
   only ever touches ``window + q_chunk`` keys, making SWA prefill O(S*w).
-* ``repro.kernels.flash_attn`` — the Pallas TPU kernel, selected with
-  ``impl='pallas'`` on real hardware (same math, tested equivalent).
+* ``repro.kernels.flash_attn`` — the Pallas TPU kernels (forward, dK/dV,
+  dQ) under one ``custom_vjp``: what ``impl='pallas'`` trains and prefills
+  with on one TPU device (same math, tested equivalent; see ``attend``).
 * ``decode_attend`` — one-token GQA attention against a (possibly ring)
   KV cache: a masked einsum, O(cache) per step.
 
@@ -23,8 +24,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.dist.sharding import shard
-from repro.kernels.flash_attn.ops import flash_attention
+from repro.dist.sharding import current_mesh, shard
+from repro.kernels.flash_attn.kernel import flash_attn_pallas
+from repro.kernels.gram.ops import on_tpu
 from repro.models import layers
 from repro.models.config import ModelConfig
 
@@ -140,19 +142,33 @@ def xla_flash(q, k, v, *, causal=True, window=None, scale=None,
     return out.astype(q.dtype)
 
 
+def _kernel_runs_here() -> bool:
+    """The Pallas kernels run on a TPU, and only where no mesh of several
+    devices is active: GSPMD cannot partition a ``pallas_call`` (that
+    would take a ``shard_map`` around it)."""
+    mesh = current_mesh()
+    return on_tpu() and (mesh is None or mesh.size == 1)
+
+
 def attend(q, k, v, *, causal=True, window=None, scale=None, impl="xla",
            kv_valid=None):
-    """Dispatch: XLA chunked flash (default / dry-run) or Pallas kernel."""
-    if impl == "xla":
+    """q: (B, H, Sq, D); k/v: (B, KV, Sk, D) -> (B, H, Sq, D).
+
+    ``impl="pallas"`` takes the Pallas kernels where they run (one TPU
+    device) and the cache has no ragged ``kv_valid`` mask; everywhere else
+    (the CPU, the host dry-run, a multi-device mesh, ragged caches) it
+    takes ``xla_flash``, as ``impl="xla"`` always does.
+    ``impl="pallas_interpret"`` runs the kernels in the Pallas interpreter
+    (tests)."""
+    if kv_valid is not None or impl == "xla" or (
+            impl == "pallas" and not _kernel_runs_here()):
         return xla_flash(q, k, v, causal=causal, window=window, scale=scale,
                          kv_valid=kv_valid)
-    KV = k.shape[1]
-    H = q.shape[1]
-    if H != KV:  # kernel is MHA-layout; expand kv (TPU path; G small)
-        k = jnp.repeat(k, H // KV, axis=1)
-        v = jnp.repeat(v, H // KV, axis=1)
-    return flash_attention(q, k, v, causal=causal, window=window, scale=scale,
-                           impl=impl)
+    if impl not in ("pallas", "pallas_interpret"):
+        raise ValueError(f"unknown attention impl {impl!r}")
+    return flash_attn_pallas(q, k, v, causal=causal, window=window,
+                             scale=scale,
+                             interpret=impl == "pallas_interpret")
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.coord_stats.kernel import (coord_stats_pallas,
                                               krum_scores_pallas)
+from repro.kernels.flash_attn.kernel import flash_attn_pallas
 from repro.kernels.gram.kernel import tree_gram_pallas
 from repro.kernels.weighted_sum.kernel import weighted_sum_pallas
 
@@ -62,9 +63,9 @@ def _compile(fn, *shapes):
 def _kernel_names(hlo: str) -> set[str]:
     """The instruction names of the Mosaic kernels, less their ``.N``: the
     names the profiler gives their op events, which the benchmark's
-    kernel metrics match."""
+    kernel metrics match.  A kernel's type may be a tuple of outputs."""
     return {m.group(1) for m in re.finditer(
-        r"%([\w\-]+?)(?:\.\d+)? = \S+ custom-call\(.*"
+        r"%([\w\-]+?)(?:\.\d+)? = [^=]*? custom-call\(.*"
         r'custom_call_target="tpu_custom_call"', hlo)}
 
 
@@ -110,3 +111,30 @@ def test_selection_kernel_names(one_chip):
         lambda G, D: (coord_stats_pallas(G, op="median", f=1),
                       krum_scores_pallas(D, f=3)), g, d2)
     assert _kernel_names(hlo) == {"coord_stats_pallas", "krum_scores_pallas"}
+
+
+def test_flash_attention_fwd_bwd_cell_widths(one_chip):
+    """The flash-attention forward, dK/dV and dQ kernels at the benchmark
+    cells' widths (SmolLM: 15 heads, 5 K/V heads, d 64; seq 2048, bf16),
+    under a vmap of the 4 workers as the train step calls them: Mosaic
+    takes the shape-derived blocks, and the step holds no (S, S) array."""
+    cfg = SMOLLM
+    H, KV, D, S = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, 2048
+    q = jax.ShapeDtypeStruct((W, 1, H, S, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((W, 1, KV, S, D), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def fwd_bwd(q, k, v, do):
+        def one(q, k, v, do):
+            o, vjp = jax.vjp(flash_attn_pallas, q, k, v)
+            return o, vjp(do)
+        return jax.vmap(one)(q, k, v, do)
+
+    hlo, total = _compile(fwd_bwd, q, kv, kv, q)
+    assert _kernel_names(hlo) == {"flash_attn_fwd", "flash_attn_bwd_dkv",
+                                  "flash_attn_bwd_dq"}
+    # bf16 q, k, v, do in and o, dq, dk, dv out: 2 x 2 (H + KV) heads
+    io = 2 * 2 * 2 * W * (H + KV) * S * D
+    # beside them only O(S) residuals (o, lse, di); the fp32 scores of one
+    # head would be W * S * S * 4 = 67 MB, of all heads 1 GB
+    assert total < 1.25 * io
